@@ -1,6 +1,7 @@
 """Executable-collective benchmark: our shard_map ALLREDUCEs on 8 fake CPU
-devices (numerics + wall time) — run in a subprocess so the main process
-keeps its single real device.
+devices (numerics + wall time) — run in a subprocess pinned to
+``JAX_PLATFORMS=cpu``, so the child never asks for an accelerator the
+parent process may already hold.  A child that fails raises.
 
 CPU wall-times don't transfer to TPU; the useful derived outputs are the
 numerical max-error vs psum and the per-algorithm round counts (which ARE
@@ -10,11 +11,24 @@ the TPU-relevant α structure).
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
+
+
+def _run_cpu_child(script: str, bench: str) -> dict:
+    """Run ``script`` on the CPU backend; return its ``RESULT`` payload."""
+    r = subprocess.run([sys.executable, "-c", script.format(src=SRC)],
+                       capture_output=True, text=True, timeout=900,
+                       env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    for line in r.stdout.splitlines():
+        if r.returncode == 0 and line.startswith("RESULT"):
+            return json.loads(line[6:])
+    raise RuntimeError(f"{bench}: CPU child failed (rc={r.returncode}):\n"
+                       f"{r.stderr[-2000:]}")
 
 SCRIPT = """
 import os
@@ -22,13 +36,12 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, time
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import make_all_reduce
 from repro.core.scheduler import build_schedule
 
 p = 8
-mesh = compat.make_mesh((p,), ("d",))
+mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(0)
 x = rng.randn(p, 1 << 16).astype(np.float32)
 expect = x.sum(0)
@@ -50,17 +63,10 @@ print("RESULT" + json.dumps(out))
 
 def run() -> list[str]:
     lines = ["name,us_per_call,derived"]
-    env = {"PATH": "/usr/bin:/bin:/usr/local/bin", "HOME": "/root"}
-    r = subprocess.run([sys.executable, "-c", SCRIPT.format(src=SRC)],
-                       capture_output=True, text=True, timeout=900, env=env)
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT"):
-            data = json.loads(line[6:])
-            for algo, d in data.items():
-                lines.append(f"bench_collective_exec/{algo}/8dev_256KB,{d['us']:.0f},"
-                             f"err={d['err']:.1e} rounds={d['rounds']}")
-            return lines
-    lines.append(f"bench_collective_exec/error,,{r.stderr[-200:]}")
+    data = _run_cpu_child(SCRIPT, "bench_collective_exec")
+    for algo, d in data.items():
+        lines.append(f"bench_collective_exec/{algo}/8cpu_256KB,{d['us']:.0f},"
+                     f"err={d['err']:.1e} rounds={d['rounds']}")
     return lines
 
 
@@ -75,15 +81,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys, json, time
 sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from repro import compat
-from jax.sharding import PartitionSpec as P, NamedSharding
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import (compile_schedule, make_overlapped_all_reduce,
                                     schedule_for_execution)
 from repro.kernels import ops
 
 p = 8
 D = 128
-mesh = compat.make_mesh((p,), ("d",))
+mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(0)
 x = rng.randn(p, 1 << 16).astype(np.float32)
 xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("d", None)))
@@ -103,7 +108,7 @@ def timed(f):
 expect = np.asarray(compute(jnp.asarray(x.sum(0))))
 out = {{}}
 mono_fn = compile_schedule(schedule_for_execution("lumorph2", p), "d")
-mono = jax.jit(compat.shard_map(
+mono = jax.jit(jax.shard_map(
     lambda v: compute(mono_fn(v[0]))[None], mesh=mesh,
     in_specs=P("d", None), out_specs=P("d", None),
     axis_names={{"d"}}, check_vma=False))
@@ -135,21 +140,12 @@ def run_overlap() -> list[str]:
     from repro.core import cost_model as cm
 
     lines = ["name,us_per_call,derived"]
-    env = {"PATH": "/usr/bin:/bin:/usr/local/bin", "HOME": "/root"}
-    r = subprocess.run([sys.executable, "-c", OVERLAP_SCRIPT.format(src=SRC)],
-                       capture_output=True, text=True, timeout=900, env=env)
-    data = None
-    for line in r.stdout.splitlines():
-        if line.startswith("RESULT"):
-            data = json.loads(line[6:])
-    if data is None:
-        lines.append(f"bench_overlap/error,,{r.stderr[-200:]}")
-    else:
-        mono_us = data["mono"]["us"]
-        for name, d in data.items():
-            ratio = "" if name == "mono" else f" vs_mono={mono_us / d['us']:.2f}x"
-            lines.append(f"bench_overlap/exec/{name}/8dev_256KB,{d['us']:.0f},"
-                         f"err={d['err']:.1e}{ratio}")
+    data = _run_cpu_child(OVERLAP_SCRIPT, "bench_overlap")
+    mono_us = data["mono"]["us"]
+    for name, d in data.items():
+        ratio = "" if name == "mono" else f" vs_mono={mono_us / d['us']:.2f}x"
+        lines.append(f"bench_overlap/exec/{name}/8cpu_256KB,{d['us']:.0f},"
+                     f"err={d['err']:.1e}{ratio}")
 
     link = cm.LUMORPH_LINK
     for p in (64, CLAIM_P):

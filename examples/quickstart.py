@@ -15,11 +15,9 @@ import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.core import cost_model as cm
 from repro.core.collectives import make_all_reduce
@@ -56,7 +54,7 @@ def main():
           f"{ring*1e6:.1f}µs → {1 - ours/ring:.0%} faster")
 
     # -- 4. executable collectives --------------------------------------------
-    mesh = compat.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     x = np.random.RandomState(0).randn(8, 1000).astype(np.float32)
     xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("data", None)))
     for algo in ("ring", "lumorph2", "lumorph4"):

@@ -33,7 +33,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.scheduler import (ChunkedSchedule, Schedule, build_schedule,
                                   chunk_schedule)
 
@@ -91,7 +90,7 @@ def compile_schedule(schedule: Schedule, axis_name: str,
     n_chunks = schedule.n_chunks
 
     def fn(x: Array) -> Array:
-        axis = compat.axis_size(axis_name)
+        axis = jax.lax.axis_size(axis_name)
         if axis != p:
             raise ValueError(
                 f"schedule has {p} participants but axis {axis_name!r} is "
@@ -152,7 +151,7 @@ def schedule_for_execution(algo: str, p: int,
 
 def _compiled(algo: str):
     def run(x: Array, axis_name: str) -> Array:
-        p = compat.axis_size(axis_name)
+        p = jax.lax.axis_size(axis_name)
         return compile_schedule(schedule_for_execution(algo, p), axis_name)(x)
     run.__name__ = f"{algo}_all_reduce"
     return run
@@ -175,7 +174,7 @@ def all_reduce(x: Array, axis_name: str, algo: str = "lumorph2") -> Array:
     ``lumorph2`` builder applies the same fallback, so dispatch and IR
     agree by construction.)
     """
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if algo in ("lumorph2",) and p & (p - 1):
         algo = "ring"
     try:
@@ -215,7 +214,7 @@ def overlapped_all_reduce(x: Array, axis_name: str, algo: str = "lumorph2",
     pod-built ``hier:*`` Schedule (or a prebuilt :class:`ChunkedSchedule`)
     whose participant count matches the axis.
     """
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if schedule is None:
         a = algo
         if a in ("lumorph2",) and p & (p - 1):
@@ -272,7 +271,7 @@ def make_overlapped_all_reduce(mesh: Mesh, axis_name: str,
                                ) -> Callable[[Array], Array]:
     """Jitted global-array wrapper of :func:`overlapped_all_reduce` (the
     chunked sibling of :func:`make_all_reduce`; same sharding contract)."""
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda v: overlapped_all_reduce(v[0], axis_name, algo,
                                         n_chunks=n_chunks, compute=compute,
                                         schedule=schedule)[None],
@@ -293,7 +292,7 @@ def make_all_reduce(mesh: Mesh, axis_name: str, algo: str = "lumorph2",
     (one slice per chip); output is identically sharded, every slice holding
     the sum.  Used by tests and the gradient-communication layer.
     """
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda v: all_reduce(v[0], axis_name, algo)[None],
         mesh=mesh,
         in_specs=P(axis_name),

@@ -11,8 +11,9 @@ TPU-native design (not a CUDA port):
   * causal + sliding-window masking via block-position iota; fully-masked
     blocks still iterate but skip the matmul through ``@pl.when``.
 
-Validated against ``ref.reference_attention`` in interpret mode (this
-container is CPU-only; TPU is the deployment target).
+Validated against ``ref.reference_attention`` in interpret mode on CPU
+(``tests/test_kernels.py``) and on the chip (``chip_smoke.py``);
+``tests/test_tpu_compile.py`` compiles it for a described v5e.
 """
 
 from __future__ import annotations
@@ -82,7 +83,7 @@ def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = True, window: Optional[int] = None,
                          scale: Optional[float] = None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """q: [BH, Sq, D]; k/v: [BKV, Skv, D] with BH = BKV·n_rep.  → [BH, Sq, D].
 
     BH-major layout: head index varies fastest within a batch entry so the

@@ -13,6 +13,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 QUANT_BLOCK = 256
+#: rows per grid step; the 1-D per-row scales are tiled T(1024) by XLA on
+#: TPU, and Mosaic refuses a scale block that does not match that tile
+SCALE_BLOCK = 1024
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
@@ -28,14 +31,14 @@ def _dequant_kernel(q_ref, s_ref, o_ref):
     o_ref[...] = q_ref[...].astype(jnp.float32) * s_ref[...][:, None]
 
 
-def quantize_int8_pallas(x: jax.Array, block_rows: int = 512,
-                         interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+def quantize_int8_pallas(x: jax.Array, *,
+                         interpret: bool) -> tuple[jax.Array, jax.Array]:
     """flat fp32 x → (int8 payload, per-block fp32 scales)."""
     n = x.shape[0]
     pad = (-n) % QUANT_BLOCK
     x2 = jnp.pad(x.astype(jnp.float32), (0, pad)).reshape(-1, QUANT_BLOCK)
     rows = x2.shape[0]
-    br = min(block_rows, rows)
+    br = min(SCALE_BLOCK, rows)
     rpad = (-rows) % br
     if rpad:
         x2 = jnp.pad(x2, ((0, rpad), (0, 0)))
@@ -52,12 +55,11 @@ def quantize_int8_pallas(x: jax.Array, block_rows: int = 512,
     return q[:rows].reshape(-1)[: n + pad][:n + pad], s[:rows]
 
 
-def dequantize_int8_pallas(q: jax.Array, scales: jax.Array, n: int,
-                           block_rows: int = 512,
-                           interpret: bool = True) -> jax.Array:
+def dequantize_int8_pallas(q: jax.Array, scales: jax.Array, n: int, *,
+                           interpret: bool) -> jax.Array:
     q2 = q.reshape(-1, QUANT_BLOCK)
     rows = q2.shape[0]
-    br = min(block_rows, rows)
+    br = min(SCALE_BLOCK, rows)
     rpad = (-rows) % br
     if rpad:
         q2 = jnp.pad(q2, ((0, rpad), (0, 0)))

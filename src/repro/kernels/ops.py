@@ -1,7 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` auto-selects: real kernels on TPU, interpreter elsewhere
-(this container is CPU-only; TPU is the deployment target).
+This is the one place that chooses ``interpret``: compiled Mosaic kernels
+on TPU, the Pallas interpreter on any other backend.  The kernel modules
+take ``interpret`` as a required argument, so no caller gets the
+interpreter on the chip by default.
 """
 
 from __future__ import annotations

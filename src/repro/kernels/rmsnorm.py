@@ -23,7 +23,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_pallas(x: jax.Array, w: jax.Array, eps: float = 1e-6,
-                   block_rows: int = 128, interpret: bool = True) -> jax.Array:
+                   block_rows: int = 128, *, interpret: bool) -> jax.Array:
     """x: [..., d]; w: [d] (stored as residual scale, applied as 1+w)."""
     shape = x.shape
     d = shape[-1]

@@ -13,19 +13,19 @@ jax initialization).
 from __future__ import annotations
 
 import jax
-
-from repro.compat import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
     """Small mesh over however many (real or fake) devices exist — tests."""
-    return make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def data_axes(mesh: jax.sharding.Mesh) -> tuple[str, ...]:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke_config
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import transformer as tf
 from repro.models import attention as attn_lib
@@ -93,4 +94,5 @@ def main(argv=None) -> dict:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig
 from repro.models import transformer as tf
 from repro.optim import grad_comm
@@ -176,7 +175,7 @@ def make_train_step(cfg: ModelConfig, policy: ShardingPolicy,
     def step(params, opt_state, batch):
         specs_b = batch_spec_fn(batch)
         o_spec = rep({k: v for k, v in opt_state.items()})
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rep(params), o_spec, specs_b),
             out_specs=(rep(params), o_spec, P()),

@@ -3,8 +3,9 @@
 The end-to-end driver (deliverable b): builds the model, the sharding
 policy, the LUMORPH gradient-communication backend, the deterministic data
 stream, and runs a checkpointed training loop with automatic restart from
-the latest checkpoint.  On this CPU container use ``--smoke`` (reduced
-config); the same flags drive the full configs on a real pod.
+the latest checkpoint.  ``--smoke`` selects the reduced config for CPU
+runs; without it the published widths run (``chip_smoke.py`` drives
+BERT-large this way on one TPU v5e chip).
 
 Example (paper's regime — BERT, data-parallel, LUMORPH-4 collectives):
   PYTHONPATH=src python -m repro.launch.train --arch bert-large --smoke \
@@ -24,6 +25,7 @@ from repro.checkpoint import checkpoint as ckpt_lib
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, stream
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim.adamw import AdamWConfig
 from repro.sharding.policy import make_policy
@@ -86,13 +88,15 @@ def main(argv=None) -> dict:
         print(f"[train] restored checkpoint at step {start_step}", flush=True)
 
     data = DataConfig(seed=args.seed, global_batch=args.batch, seq_len=args.seq)
-    losses = []
+    losses, step_s = [], []
     t_start = time.time()
     for step, batch in stream(cfg, data, start_step):
         if step >= args.steps:
             break
+        t0 = time.perf_counter()
         params, opt_state, loss = train_step(params, opt_state, batch)
-        losses.append(float(loss))
+        losses.append(float(loss))  # float() waits for the step to finish
+        step_s.append(time.perf_counter() - t0)
         if step % args.log_every == 0 or step == args.steps - 1:
             print(f"[train] step={step:5d} loss={float(loss):.4f} "
                   f"({(time.time()-t_start)/max(step-start_step+1,1):.2f}s/step)",
@@ -102,10 +106,14 @@ def main(argv=None) -> dict:
     result = {"final_loss": losses[-1] if losses else None,
               "first_loss": losses[0] if losses else None,
               "steps": len(losses), "comm": args.comm,
-              "overlap": args.overlap}
+              "overlap": args.overlap, "losses": losses, "step_s": step_s,
+              # devices that hold the trained parameters
+              "param_devices": len(set().union(
+                  *(leaf.sharding.device_set for leaf in jax.tree.leaves(params))))}
     print(json.dumps(result))
     return result
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -30,8 +30,6 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
-
 from repro.core import collectives
 from repro.core.cost_model import LUMORPH_LINK, LinkModel, select_algorithm
 
@@ -118,7 +116,7 @@ def compressed_all_reduce(x: Array, axis_name: str,
     1/C slice with the same per-block scales machinery, so compression and
     overlap stack rather than exclude each other.
     """
-    p = compat.axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if p == 1:
         return x
     if p & (p - 1):
@@ -189,7 +187,7 @@ def all_reduce_grads(grads: PyTree, axis_names: tuple[str, ...],
     buckets = make_buckets(flat.size, bucket_bytes)
 
     axis = axis_names if len(axis_names) > 1 else axis_names[0]
-    p_total = compat.axis_size(axis)
+    p_total = jax.lax.axis_size(axis)
 
     log: list[tuple[int, str]] = []
     reduced_parts = []

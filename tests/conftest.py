@@ -1,25 +1,6 @@
 """Shared fixtures. NOTE: no XLA_FLAGS here — tests see the real (1) device;
 only the dry-run pins 512 fake devices, and multi-device collective tests
-spawn subprocesses with their own flags.
-
-Also installs the deterministic `hypothesis` stand-in from
-``_hypothesis_stub.py`` when the real package (an optional test extra) is
-absent, so the property tests collect and run everywhere."""
-
-import importlib.util
-import pathlib
-import sys
-
-try:
-    import hypothesis  # noqa: F401  (the real thing wins when installed)
-except ModuleNotFoundError:
-    _spec = importlib.util.spec_from_file_location(
-        "_hypothesis_stub", pathlib.Path(__file__).with_name("_hypothesis_stub.py"))
-    _stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_stub)
-    _mod = _stub._as_module()
-    sys.modules["hypothesis"] = _mod
-    sys.modules["hypothesis.strategies"] = _mod.strategies
+spawn subprocesses with their own flags."""
 
 import jax
 import pytest

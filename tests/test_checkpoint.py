@@ -3,11 +3,10 @@
 from pathlib import Path
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.checkpoint import checkpoint as ck
 
@@ -69,7 +68,7 @@ def test_elastic_restore_resharded(tmp_path):
     """Restore onto explicit shardings (elastic mesh change semantics)."""
     s = _state()
     ck.save(tmp_path, 3, s)
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = jax.tree.map(
         lambda _: jax.NamedSharding(mesh, jax.sharding.PartitionSpec()), s)
     restored, step = ck.restore(tmp_path, s, shardings=sh)

@@ -7,11 +7,10 @@ import sys
 from pathlib import Path
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -20,13 +19,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import make_all_reduce
 from repro.optim.grad_comm import compressed_all_reduce
 
 p = 8
-mesh = compat.make_mesh((p,), ("d",))
+mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(0)
 x = rng.randn(p, 41).astype(np.float32)
 expect = np.tile(x.sum(0, keepdims=True), (p, 1))
@@ -35,7 +33,7 @@ for algo in ("ring", "lumorph2", "lumorph4", "tree", "psum"):
     out = np.asarray(make_all_reduce(mesh, "d", algo)(xs))
     assert np.allclose(out, expect, rtol=1e-5, atol=1e-5), algo
 # compressed: lossy but bounded (int8 per-block ~ 1% of block max per hop)
-f = jax.jit(compat.shard_map(lambda v: compressed_all_reduce(v[0], "d")[None],
+f = jax.jit(jax.shard_map(lambda v: compressed_all_reduce(v[0], "d")[None],
             mesh=mesh, in_specs=P("d", None), out_specs=P("d", None),
             axis_names={{"d"}}, check_vma=False))
 out = np.asarray(f(xs))
@@ -57,10 +55,10 @@ def test_collectives_multidevice():
 def test_single_device_identity():
     """p=1: every algorithm must be the identity."""
     from repro.core.collectives import all_reduce
-    mesh = compat.make_mesh((1,), ("d",))
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
     x = jnp.arange(16.0)
     for algo in ("ring", "lumorph2", "lumorph4", "tree", "psum"):
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             lambda v: all_reduce(v, "d", algo), mesh=mesh,
             in_specs=jax.sharding.PartitionSpec(),
             out_specs=jax.sharding.PartitionSpec(),

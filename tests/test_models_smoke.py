@@ -3,11 +3,10 @@ step on CPU, shape + finiteness asserts; decode parity vs the parallel
 forward (the strongest single invariant the substrate has)."""
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import ASSIGNED, REGISTRY, get_smoke_config
 from repro.models import (decode_step, forward_logits, init_caches,
@@ -157,7 +156,7 @@ def test_microbatched_grads_match(rng):
     from repro.sharding.policy import make_policy
     from repro.optim.adamw import AdamWConfig
     cfg = get_smoke_config("bert-large").replace(compute_dtype="float32")
-    mesh = compat.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     policy = make_policy(cfg, mesh)
     opt_cfg = AdamWConfig(lr=1e-3, warmup_steps=0, total_steps=10)
     s1 = steps_lib.make_train_step(cfg, policy, opt_cfg, donate=False)

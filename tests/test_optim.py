@@ -1,11 +1,10 @@
 """Optimizer + gradient-communication machinery."""
 
 import jax
-
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from hypothesis import given, settings, strategies as st
 
 from repro.optim import grad_comm
@@ -93,7 +92,7 @@ def test_error_feedback_removes_bias():
 
 def test_all_reduce_grads_single_axis_identity():
     """On a 1-device mesh the bucketed LUMORPH allreduce must be exact."""
-    mesh = compat.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     grads = {"a": jnp.arange(8.0), "b": jnp.ones((3, 3))}
 
     def body(g):
@@ -101,7 +100,7 @@ def test_all_reduce_grads_single_axis_identity():
         return out
 
     specs = jax.tree.map(lambda _: jax.sharding.PartitionSpec(), grads)
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
                                  in_specs=(specs,),
                                  out_specs=specs,
                                  axis_names={"data"}, check_vma=False))

@@ -277,8 +277,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import (compile_schedule,
                                     make_overlapped_all_reduce,
                                     overlapped_all_reduce)
@@ -288,7 +287,7 @@ from repro.optim.grad_comm import _int8_decode, _int8_encode
 MODE = {mode!r}
 p = 8
 CPR = 32
-mesh = compat.make_mesh((p,), ("d",))
+mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
 flat_chips = (5, 12, 3, 40, 21, 9, 33, 18)  # scattered, noncontiguous
 pod_chips = (2, 0, 3, 1, 34, 32, 35, 33)    # 2 racks x 4, scrambled
 algos = candidate_algos(("ring", "lumorph2", "lumorph4", "tree"),
@@ -310,7 +309,7 @@ xs = jax.device_put(jnp.asarray(xf).astype(dtype),
                     NamedSharding(mesh, P("d", None)))
 
 def run(fn):
-    f = jax.jit(compat.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
+    f = jax.jit(jax.shard_map(lambda v: fn(v[0])[None], mesh=mesh,
                 in_specs=P("d", None), out_specs=P("d", None),
                 axis_names={{"d"}}, check_vma=False))
     return np.asarray(f(xs).astype(jnp.float32))

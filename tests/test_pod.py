@@ -229,8 +229,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import compile_schedule
 from repro.core.scheduler import hierarchical_schedule
 
@@ -243,12 +242,12 @@ cases = [
     (6, (0, 1, 8, 9, 16, 17), "ring"),             # 3 racks x 2
 ]
 for p, chips, intra in cases:
-    mesh = compat.make_mesh((p,), ("d",))
+    mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
     x = rng.randn(p, 37).astype(np.float32)
     expect = np.tile(x.sum(0, keepdims=True), (p, 1))
     xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("d", None)))
     sched = hierarchical_schedule(chips, 1e6, 8, intra=intra)
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda v: compile_schedule(sched, "d")(v[0])[None], mesh=mesh,
         in_specs=P("d", None), out_specs=P("d", None),
         axis_names={{"d"}}, check_vma=False))

@@ -139,13 +139,12 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=6"
 import sys; sys.path.insert(0, {src!r})
 import jax, jax.numpy as jnp, numpy as np
-from jax.sharding import PartitionSpec as P, NamedSharding
-from repro import compat
+from jax.sharding import AxisType, PartitionSpec as P, NamedSharding
 from repro.core.collectives import compile_schedule
 from repro.core.scheduler import build_schedule
 
 p = 6
-mesh = compat.make_mesh((p,), ("d",))
+mesh = jax.make_mesh((p,), ("d",), axis_types=(AxisType.Auto,))
 rng = np.random.RandomState(7)
 x = rng.randn(p, 23).astype(np.float32)
 expect = np.tile(x.sum(0, keepdims=True), (p, 1))
@@ -153,7 +152,7 @@ xs = jax.device_put(jnp.asarray(x), NamedSharding(mesh, P("d", None)))
 chips = (3, 11, 4, 40, 25, 17)  # scattered tenant: rank i plays chips[i]
 for algo in ("ring", "lumorph2", "lumorph4", "tree"):
     sched = build_schedule(algo, chips, 1e6)
-    f = jax.jit(compat.shard_map(
+    f = jax.jit(jax.shard_map(
         lambda v: compile_schedule(sched, "d")(v[0])[None], mesh=mesh,
         in_specs=P("d", None), out_specs=P("d", None),
         axis_names={{"d"}}, check_vma=False))

@@ -4,16 +4,15 @@ full-size architecture — without compiling anything (AbstractMesh)."""
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
-from repro.compat import abstract_mesh
 from repro.configs import ASSIGNED, get_config
 from repro.launch import steps as steps_lib
 from repro.models import transformer as tf
 from repro.sharding.policy import make_policy
 
-SINGLE = abstract_mesh((16, 16), ("data", "model"))
-MULTI = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+SINGLE = AbstractMesh((16, 16), ("data", "model"))
+MULTI = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def _axis_size(mesh, name):

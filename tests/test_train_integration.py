@@ -47,17 +47,7 @@ def test_compressed_training_tracks():
     assert comp["final_loss"] == pytest.approx(base["final_loss"], rel=0.05)
 
 
-def _partial_auto_ok() -> bool:
-    from repro import compat
-    return compat.supports_partial_auto_shard_map()
-
-
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not _partial_auto_ok(),
-    reason="dp=2 on 4 devices needs partial-auto shard_map (model axis "
-           "size 2); jax 0.4.x lowers it through an unsupported "
-           "PartitionId instruction")
 def test_loss_decreases_short_run():
     out = _run_train(["--arch", "bert-large", "--smoke", "--steps", "30",
                       "--batch", "4", "--seq", "32", "--lr", "1e-3",
