@@ -9,12 +9,15 @@ never one derived from a temporary name, a process id or the time.
 from __future__ import annotations
 
 import os
+import re
 from pathlib import Path
 
 import jax
 
-#: ``<checkout>/.jax_cache`` (this file is ``<checkout>/src/repro/launch/``)
-CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+#: the checkout (this file is ``<checkout>/src/repro/launch/``)
+CHECKOUT = Path(__file__).resolve().parents[3]
+#: ``<checkout>/.jax_cache``
+CHECKOUT_CACHE_DIR = CHECKOUT / ".jax_cache"
 
 
 def use_compile_cache() -> str:
@@ -25,7 +28,17 @@ def use_compile_cache() -> str:
     Otherwise the cache goes to :data:`CHECKOUT_CACHE_DIR`.  Call it from
     a script's ``__main__`` block, before the first compile — never from a
     function that tests call in-process.
+
+    Each entry's key includes the ops' metadata: their ``op_name`` (the
+    ``jax.named_scope`` names a profiler trace attributes device time to)
+    and source lines.  Without it a program that differs from a cached one
+    only in its scopes loads the cached executable, and runs under the old
+    names.  Source files are named from the checkout's root, so that the
+    same program in another checkout finds its entries.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_hlo_source_file_canonicalization_regex",
+                      "^" + re.escape(str(CHECKOUT) + os.sep))
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

@@ -156,39 +156,54 @@ def param_shapes(cfg: ModelConfig) -> PyTree:
 
 def _block_forward(kind: str, p: dict, x: Array, positions: Array,
                    cfg: ModelConfig, mask_kind: str, prefix_len: int) -> tuple[Array, Array]:
-    aux = jnp.zeros((), jnp.float32)
-    if kind in ("dense", "moe"):
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        x = x + attn.attention_forward(p["attn"], h, positions, cfg, mask_kind,
-                                       prefix_len, use_pallas=cfg.use_pallas)
-        h = apply_norm(cfg.norm, p["ln2"], x)
-        if kind == "dense":
-            x = x + apply_mlp(p["mlp"], h, cfg.mlp_style)
-        else:
-            y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k, cfg.moe_capacity_factor)
+    """One block over the full sequence, under the device-side scope
+    ``block`` (its own ops: norms, residual adds) with ``attention``,
+    ``mlp`` and ``moe`` inside it: the names a profiler trace gives the
+    block's ops, forward, backward and recomputed alike."""
+    with jax.named_scope("block"):
+        aux = jnp.zeros((), jnp.float32)
+        if kind in ("dense", "moe"):
+            h = apply_norm(cfg.norm, p["ln1"], x)
+            with jax.named_scope("attention"):
+                a = attn.attention_forward(p["attn"], h, positions, cfg, mask_kind,
+                                           prefix_len, use_pallas=cfg.use_pallas)
+            x = x + a
+            h = apply_norm(cfg.norm, p["ln2"], x)
+            if kind == "dense":
+                with jax.named_scope("mlp"):
+                    y = apply_mlp(p["mlp"], h, cfg.mlp_style)
+            else:
+                with jax.named_scope("moe"):
+                    y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k,
+                                               cfg.moe_capacity_factor)
             x = x + y
-    elif kind in ("mla_dense", "mla_moe"):
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        x = x + attn.mla_forward(p["attn"], h, positions, cfg)
-        h = apply_norm(cfg.norm, p["ln2"], x)
-        if kind == "mla_dense":
-            x = x + apply_mlp(p["mlp"], h, cfg.mlp_style)
-        else:
-            y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k, cfg.moe_capacity_factor)
+        elif kind in ("mla_dense", "mla_moe"):
+            h = apply_norm(cfg.norm, p["ln1"], x)
+            with jax.named_scope("attention"):
+                a = attn.mla_forward(p["attn"], h, positions, cfg)
+            x = x + a
+            h = apply_norm(cfg.norm, p["ln2"], x)
+            if kind == "mla_dense":
+                with jax.named_scope("mlp"):
+                    y = apply_mlp(p["mlp"], h, cfg.mlp_style)
+            else:
+                with jax.named_scope("moe"):
+                    y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k,
+                                               cfg.moe_capacity_factor)
             x = x + y
-    elif kind == "mamba2":
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        x = x + ssm.mamba2_forward(p["mix"], h, cfg.ssm_state, cfg.ssm_headdim,
-                                   cfg.ssm_expand, cfg.ssm_chunk)
-    elif kind == "mlstm":
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        x = x + ssm.mlstm_forward(p["mix"], h, cfg.n_heads, cfg.xlstm_expand)
-    elif kind == "slstm":
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        x = x + ssm.slstm_forward(p["mix"], h, cfg.n_heads)
-    else:
-        raise ValueError(kind)
-    return x, aux
+        elif kind == "mamba2":
+            h = apply_norm(cfg.norm, p["ln1"], x)
+            x = x + ssm.mamba2_forward(p["mix"], h, cfg.ssm_state, cfg.ssm_headdim,
+                                       cfg.ssm_expand, cfg.ssm_chunk)
+        elif kind == "mlstm":
+            h = apply_norm(cfg.norm, p["ln1"], x)
+            x = x + ssm.mlstm_forward(p["mix"], h, cfg.n_heads, cfg.xlstm_expand)
+        elif kind == "slstm":
+            h = apply_norm(cfg.norm, p["ln1"], x)
+            x = x + ssm.slstm_forward(p["mix"], h, cfg.n_heads)
+        else:
+            raise ValueError(kind)
+        return x, aux
 
 
 def _shared_block_forward(params: PyTree, x: Array, x0: Array, positions: Array,
@@ -244,9 +259,10 @@ def forward_logits(params: PyTree, batch: dict, cfg: ModelConfig) -> tuple[Array
     cdt = cfg.cdtype
     tokens = batch["tokens"]
     b, s = tokens.shape
-    x = params["embed"][tokens].astype(cdt)
-    if cfg.scale_embeddings:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(cdt)
+        if cfg.scale_embeddings:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, cdt)
     prefix_len = 0
     mask_kind = "causal"
     if cfg.kind == "vlm":
@@ -273,8 +289,9 @@ def forward_logits(params: PyTree, batch: dict, cfg: ModelConfig) -> tuple[Array
         x, aux = _run_segment(seg_params, kind, count, x, aux, positions, cfg,
                               mask_kind, prefix_len)
         layer_idx += count
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    logits = _unembed(params, x, cfg)
+    with jax.named_scope("head"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        logits = _unembed(params, x, cfg)
     return logits, aux
 
 
@@ -333,8 +350,10 @@ def _decoder_cross_forward(params: PyTree, x: Array, enc_out: Array,
             fwd = jax.checkpoint(block) if cfg.remat else block
             return fwd(xx, p_i), None
         x, _ = jax.lax.scan(body, x, seg)
-    x = apply_norm(cfg.norm, params["final_norm"], x)
-    return _unembed(params, x, cfg), jnp.zeros((), jnp.float32)
+    with jax.named_scope("head"):
+        x = apply_norm(cfg.norm, params["final_norm"], x)
+        logits = _unembed(params, x, cfg)
+    return logits, jnp.zeros((), jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -345,7 +364,8 @@ def loss_fn(params: PyTree, batch: dict, cfg: ModelConfig) -> Array:
     logits, aux = forward_logits(params, batch, cfg)
     if cfg.kind == "vlm":  # image positions carry no LM loss
         logits = logits[:, cfg.num_image_tokens:]
-    ce = cross_entropy(logits[:, :-1], batch["tokens"][:, 1:])
+    with jax.named_scope("head"):
+        ce = cross_entropy(logits[:, :-1], batch["tokens"][:, 1:])
     return ce + cfg.aux_loss_weight * aux
 
 

@@ -55,34 +55,37 @@ def global_norm(tree: PyTree) -> jax.Array:
 
 def adamw_update(params: PyTree, grads: PyTree, state: dict,
                  cfg: AdamWConfig) -> tuple[PyTree, dict]:
-    step = state["step"] + 1
-    gn = global_norm(grads)
-    clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gn, 1e-9))
-    lr = lr_at(cfg, step)
-    b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
-    b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
+    """One AdamW step, clipping included, under the device-side scope
+    ``optimizer``."""
+    with jax.named_scope("optimizer"):
+        step = state["step"] + 1
+        gn = global_norm(grads)
+        clip = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gn, 1e-9))
+        lr = lr_at(cfg, step)
+        b1c = 1 - cfg.b1 ** step.astype(jnp.float32)
+        b2c = 1 - cfg.b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, m, v):
-        g = g.astype(jnp.float32) * clip
-        m = cfg.b1 * m + (1 - cfg.b1) * g
-        v = cfg.b2 * v + (1 - cfg.b2) * g * g
-        mhat = m / b1c
-        vhat = v / b2c
-        p32 = p.astype(jnp.float32)
-        p32 = p32 - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * p32)
-        return p32.astype(p.dtype), m, v
+        def upd(p, g, m, v):
+            g = g.astype(jnp.float32) * clip
+            m = cfg.b1 * m + (1 - cfg.b1) * g
+            v = cfg.b2 * v + (1 - cfg.b2) * g * g
+            mhat = m / b1c
+            vhat = v / b2c
+            p32 = p.astype(jnp.float32)
+            p32 = p32 - lr * (mhat / (jnp.sqrt(vhat) + cfg.eps) + cfg.weight_decay * p32)
+            return p32.astype(p.dtype), m, v
 
-    flat_p, treedef = jax.tree.flatten(params)
-    flat_g = jax.tree.leaves(grads)
-    flat_m = jax.tree.leaves(state["m"])
-    flat_v = jax.tree.leaves(state["v"])
-    new_p, new_m, new_v = [], [], []
-    for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v):
-        a, b, c = upd(p, g, m, v)
-        new_p.append(a)
-        new_m.append(b)
-        new_v.append(c)
-    return (jax.tree.unflatten(treedef, new_p),
-            {"m": jax.tree.unflatten(treedef, new_m),
-             "v": jax.tree.unflatten(treedef, new_v),
-             "step": step})
+        flat_p, treedef = jax.tree.flatten(params)
+        flat_g = jax.tree.leaves(grads)
+        flat_m = jax.tree.leaves(state["m"])
+        flat_v = jax.tree.leaves(state["v"])
+        new_p, new_m, new_v = [], [], []
+        for p, g, m, v in zip(flat_p, flat_g, flat_m, flat_v):
+            a, b, c = upd(p, g, m, v)
+            new_p.append(a)
+            new_m.append(b)
+            new_v.append(c)
+        return (jax.tree.unflatten(treedef, new_p),
+                {"m": jax.tree.unflatten(treedef, new_m),
+                 "v": jax.tree.unflatten(treedef, new_v),
+                 "step": step})

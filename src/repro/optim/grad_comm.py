@@ -155,68 +155,78 @@ def all_reduce_grads(grads: PyTree, axis_names: tuple[str, ...],
     (differentially tested in ``tests/test_overlap.py``); ``1`` keeps the
     bit-exact monolithic path.
 
-    Returns (reduced_grads, new_error_feedback, bucket_log) where
-    bucket_log records (bytes, algo) per bucket for EXPERIMENTS.md.
+    Returns (reduced_grads, new_error_feedback, bucket_log).  bucket_log
+    is the trace-time record of the buckets, one (bytes, algo) per bucket
+    in order; its index ``i`` names the device-side scope
+    ``grad_comm/bucket<i>`` that holds that bucket's collective.  The rest
+    of the exchange sits under ``grad_comm/pack`` (flatten, cast,
+    concatenate) and ``grad_comm/unpack`` (concatenate, cast, mean, back to
+    leaves), so a profiler trace splits wire from element-wise work.
 
     Multiple dp axes (pod, data) are **flattened into one product axis**
-    (ppermute partner maps over the combined index) — a composed per-axis
-    hierarchy ships ~2× the bytes (each level re-reduces the full buffer;
-    measured in EXPERIMENTS.md §Perf c3).  Payloads travel as ``wire_dtype``
+    (ppermute partner maps over the combined index): a composed per-axis
+    hierarchy would ship about twice the bytes, since each level re-reduces
+    the full buffer.  Payloads travel as ``wire_dtype``
     (bf16 by default — gradients are bf16-born in mixed-precision training;
     accumulation happens in fp32 after each hop via the algorithms' adds).
     """
-    leaves, treedef = jax.tree.flatten(grads)
-    ef_new_leaves: Optional[list[Array]] = None
-    if compress and error_feedback is not None:
-        # EF-SGD (Karimireddy et al.): compensate with last step's residual,
-        # store the *local* quantization residual for the next step.  The
-        # per-hop requantization inside the collective adds further (small,
-        # uncompensated) error — see DESIGN.md §8.
-        ef_leaves = jax.tree.leaves(error_feedback)
-        comp = [g.astype(jnp.float32) + e for g, e in zip(leaves, ef_leaves)]
-        ef_new_leaves = []
-        for c in comp:
-            q, sc = quantize_int8(c.reshape(-1))
-            deq = dequantize_int8(q, sc, c.size).reshape(c.shape)
-            ef_new_leaves.append(c - deq)
-        leaves = comp
-    shapes = [l.shape for l in leaves]
-    sizes = [l.size for l in leaves]
-    comm_dtype = jnp.float32 if compress else wire_dtype
-    flat = jnp.concatenate([l.astype(comm_dtype).reshape(-1) for l in leaves])
-    buckets = make_buckets(flat.size, bucket_bytes)
+    with jax.named_scope("grad_comm"):
+        with jax.named_scope("pack"):
+            leaves, treedef = jax.tree.flatten(grads)
+            ef_new_leaves: Optional[list[Array]] = None
+            if compress and error_feedback is not None:
+                # EF-SGD (Karimireddy et al.): compensate with last step's
+                # residual, store the *local* quantization residual for the
+                # next step.  The per-hop requantization inside the collective
+                # adds further (small, uncompensated) error.
+                ef_leaves = jax.tree.leaves(error_feedback)
+                comp = [g.astype(jnp.float32) + e for g, e in zip(leaves, ef_leaves)]
+                ef_new_leaves = []
+                for c in comp:
+                    q, sc = quantize_int8(c.reshape(-1))
+                    deq = dequantize_int8(q, sc, c.size).reshape(c.shape)
+                    ef_new_leaves.append(c - deq)
+                leaves = comp
+            shapes = [l.shape for l in leaves]
+            sizes = [l.size for l in leaves]
+            comm_dtype = jnp.float32 if compress else wire_dtype
+            flat = jnp.concatenate([l.astype(comm_dtype).reshape(-1) for l in leaves])
+        buckets = make_buckets(flat.size, bucket_bytes)
 
-    axis = axis_names if len(axis_names) > 1 else axis_names[0]
-    p_total = jax.lax.axis_size(axis)
+        axis = axis_names if len(axis_names) > 1 else axis_names[0]
+        p_total = jax.lax.axis_size(axis)
 
-    log: list[tuple[int, str]] = []
-    reduced_parts = []
-    for b in buckets:
-        piece = flat[b.start:b.end]
-        n_bytes = piece.size * jnp.dtype(comm_dtype).itemsize
-        chosen = algo
-        if algo == "auto":
-            chosen = select_algorithm(n_bytes, p_total, link)
-        log.append((n_bytes, chosen + ("+int8" if compress else "")
-                    + (f"+ovl{overlap_chunks}" if overlap_chunks > 1 else "")))
-        if compress:
-            piece = compressed_all_reduce(piece, axis, n_chunks=overlap_chunks)
-        elif overlap_chunks > 1:
-            piece = collectives.overlapped_all_reduce(
-                piece, axis, chosen, n_chunks=overlap_chunks)
-        else:
-            piece = collectives.all_reduce(piece, axis, chosen)
-        reduced_parts.append(piece)
-    reduced = jnp.concatenate(reduced_parts) if len(reduced_parts) > 1 else reduced_parts[0]
-    reduced = reduced.astype(jnp.float32)
-    if mean:
-        reduced = reduced / p_total
-    out_leaves = []
-    off = 0
-    orig = jax.tree.leaves(grads)
-    for shp, n, g in zip(shapes, sizes, orig):
-        out_leaves.append(reduced[off:off + n].reshape(shp).astype(g.dtype))
-        off += n
-    new_ef = (jax.tree.unflatten(treedef, ef_new_leaves)
-              if ef_new_leaves is not None else None)
-    return jax.tree.unflatten(treedef, out_leaves), new_ef, log
+        log: list[tuple[int, str]] = []
+        reduced_parts = []
+        for i, b in enumerate(buckets):
+            with jax.named_scope(f"bucket{i}"):
+                piece = flat[b.start:b.end]
+                n_bytes = piece.size * jnp.dtype(comm_dtype).itemsize
+                chosen = algo
+                if algo == "auto":
+                    chosen = select_algorithm(n_bytes, p_total, link)
+                log.append((n_bytes, chosen + ("+int8" if compress else "")
+                            + (f"+ovl{overlap_chunks}" if overlap_chunks > 1 else "")))
+                if compress:
+                    piece = compressed_all_reduce(piece, axis, n_chunks=overlap_chunks)
+                elif overlap_chunks > 1:
+                    piece = collectives.overlapped_all_reduce(
+                        piece, axis, chosen, n_chunks=overlap_chunks)
+                else:
+                    piece = collectives.all_reduce(piece, axis, chosen)
+                reduced_parts.append(piece)
+        with jax.named_scope("unpack"):
+            reduced = (jnp.concatenate(reduced_parts) if len(reduced_parts) > 1
+                       else reduced_parts[0])
+            reduced = reduced.astype(jnp.float32)
+            if mean:
+                reduced = reduced / p_total
+            out_leaves = []
+            off = 0
+            orig = jax.tree.leaves(grads)
+            for shp, n, g in zip(shapes, sizes, orig):
+                out_leaves.append(reduced[off:off + n].reshape(shp).astype(g.dtype))
+                off += n
+        new_ef = (jax.tree.unflatten(treedef, ef_new_leaves)
+                  if ef_new_leaves is not None else None)
+        return jax.tree.unflatten(treedef, out_leaves), new_ef, log

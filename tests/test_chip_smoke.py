@@ -94,3 +94,35 @@ def test_compile_cache_defaults_to_checkout(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == got
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_keys_on_op_names(monkeypatch):
+    """Programs that differ only in a scope name get different cache keys;
+    a source file is named from the checkout's root, so one program in two
+    checkouts gets one key."""
+    import numpy as np
+    from jax._src import cache_key, compiler
+
+    flags = ("jax_compilation_cache_include_metadata_in_key",
+             "jax_hlo_source_file_canonicalization_regex")
+    before = {k: getattr(jax.config, k) for k in flags}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+
+    def key_of(scope: str, path: str) -> str:
+        code = f"def f(x):\n    with jax.named_scope({scope!r}):\n        return x * 2\n"
+        ns = {"jax": jax}
+        exec(compile(code, path, "exec"), ns)
+        lowered = jax.jit(ns["f"]).lower(jax.ShapeDtypeStruct((8,), "float32"))
+        return cache_key.get(lowered.compiler_ir("stablehlo"), np.array(jax.devices()[:1]),
+                             compiler.get_compile_options(1, 1), jax.devices()[0].client)
+
+    try:
+        compile_cache.use_compile_cache()
+        here = str(compile_cache.CHECKOUT / "src" / "mod.py")
+        attention, mlp, mlp_elsewhere = [key_of(scope, path) for scope, path in (
+            ("attention", here), ("mlp", here), ("mlp", os.path.join("src", "mod.py")))]
+        assert attention != mlp
+        assert mlp == mlp_elsewhere
+    finally:
+        for k, v in before.items():
+            jax.config.update(k, v)
