@@ -12,9 +12,11 @@ same object.
 All compiled programs run **inside** ``shard_map`` over a named mesh axis
 and compute a mathematically exact ALLREDUCE (validated against
 ``lax.psum``).  Rounds are Python-level loops (log p or p−1 iterations)
-so every round has static shapes; the data-dependent part (which chunks
-to ship) gathers per-rank rows of the IR's static chunk tables with the
-traced ``axis_index``.
+so every round has static shapes.  The buffer stays one flat vector:
+every rank's row of a transfer's chunk tables is one contiguous run of
+equal length, so a hop reads and writes a single span, whose offset is
+the only data-dependent part (a small static table indexed by the traced
+``axis_index``).
 
 :func:`compile_schedule` also accepts a per-hop **payload transform**
 (``encode``/``decode``) — e.g. int8 quantization with per-block scales
@@ -33,8 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.scheduler import (ChunkedSchedule, Schedule, build_schedule,
-                                  chunk_schedule)
+from repro.core.scheduler import (ChunkedSchedule, Schedule, Transfer,
+                                  build_schedule, chunk_schedule)
 
 __all__ = ["compile_schedule", "schedule_for_execution", "chunk_schedule",
            "ChunkedSchedule", "overlapped_all_reduce", "all_reduce",
@@ -68,6 +70,31 @@ def _unflatten(flat: Array, n: int, shape) -> Array:
 # the schedule -> shard_map compiler
 # ---------------------------------------------------------------------------
 
+def _runs(t: Transfer, p: int, n_chunks: int) -> tuple[np.ndarray, np.ndarray, int]:
+    """First chunk of each rank's send and recv run, and the run length.
+
+    Raises ``ValueError`` unless every rank's row of both tables is one
+    ascending run of ``k`` consecutive chunks inside the buffer — the
+    shape every builder emits, and the one the span lowering relies on.
+    """
+    send = np.asarray(t.send, dtype=np.int64)
+    recv = np.asarray(t.recv, dtype=np.int64)
+    if send.ndim != 2 or send.shape != recv.shape or send.shape[0] != p:
+        raise ValueError(
+            f"transfer tables must both be ({p}, k); got send "
+            f"{send.shape}, recv {recv.shape}")
+    k = send.shape[1]
+    step = np.arange(k)
+    for name, table in (("send", send), ("recv", recv)):
+        first = table[:, 0]
+        if (not np.array_equal(table, first[:, None] + step)
+                or first.min() < 0 or first.max() + k > n_chunks):
+            raise ValueError(
+                f"{name} table is not one contiguous run of {k} chunks per "
+                f"rank within {n_chunks} chunks: {table.tolist()}")
+    return send[:, 0], recv[:, 0], k
+
+
 def compile_schedule(schedule: Schedule, axis_name: str,
                      encode: Optional[Encode] = None,
                      decode: Optional[Decode] = None) -> Callable[[Array], Array]:
@@ -75,19 +102,32 @@ def compile_schedule(schedule: Schedule, axis_name: str,
 
     The returned function must be called inside ``shard_map``; rank ``i``
     of the mesh axis plays ``schedule.participants[i]``.  Each
-    :class:`Transfer` becomes one ``ppermute``: ranks gather their row of
-    the transfer's chunk tables (static arrays indexed by the traced
-    ``axis_index``), ship those chunks, and either accumulate or overwrite
-    the received ones.  ``encode``/``decode`` wrap every hop's payload
-    (quantization, dtype casts, …); ``decode`` receives the original piece
-    as its shape/dtype witness.
+    :class:`Transfer` becomes one ``ppermute`` of one contiguous span of
+    the flat buffer: a rank slices its send run at the offset its row of
+    the chunk tables gives (looked up with the traced ``axis_index``),
+    ships it, and adds the received span into, or writes it over, its recv
+    run.  Where only some ranks are destinations of an overwrite, the
+    others keep their span (``ppermute`` hands them zeros).  Tables that
+    are not one contiguous run per rank raise ``ValueError`` here.
+    ``encode``/``decode`` wrap every hop's payload (quantization, dtype
+    casts, …); ``decode`` receives the original span as its shape/dtype
+    witness.
     """
     # execution is the one consumer that needs the per-rank chunk tables:
     # build them now (pricing/simulation read only the schedule's shape)
     schedule.materialize()
     p = len(schedule.participants)
-    rounds = schedule.rounds
     n_chunks = schedule.n_chunks
+    hops = []
+    for rnd in schedule.rounds:
+        for t in rnd.transfers:
+            send0, recv0, k = _runs(t, p, n_chunks)
+            is_dst = np.zeros((p,), dtype=bool)
+            for _, d in t.perm:
+                is_dst[d] = True
+            # an overwrite must not clobber ranks that receive nothing
+            dst = None if t.reduce or is_dst.all() else is_dst
+            hops.append((t.perm, t.reduce, send0, recv0, k, dst))
 
     def fn(x: Array) -> Array:
         axis = jax.lax.axis_size(axis_name)
@@ -95,34 +135,33 @@ def compile_schedule(schedule: Schedule, axis_name: str,
             raise ValueError(
                 f"schedule has {p} participants but axis {axis_name!r} is "
                 f"{axis}-wide — a mismatched perm would silently drop ranks")
-        if p == 1 or not rounds:
+        if p == 1 or not hops:
             return x
         idx = jax.lax.axis_index(axis_name)
         shape = x.shape
         flat, n = _flatten_pad(x, n_chunks)
-        buf = flat.reshape(n_chunks, flat.shape[0] // n_chunks)
-        for rnd in rounds:
-            for t in rnd.transfers:
-                send_ids = jnp.asarray(t.send)[idx]
-                recv_ids = jnp.asarray(t.recv)[idx]
-                piece = jnp.take(buf, send_ids, axis=0)
-                payload = encode(piece) if encode is not None else piece
-                got = jax.tree.map(
-                    lambda a: jax.lax.ppermute(a, axis_name, t.perm), payload)
-                if decode is not None:
-                    got = decode(got, piece)
-                if t.reduce:
-                    # non-destinations receive zeros: accumulating is a no-op
-                    buf = buf.at[recv_ids].add(got)
-                else:
-                    # overwrite only on actual destinations; ppermute hands
-                    # everyone else zeros that must not clobber their chunks
-                    is_dst = np.zeros((p,), dtype=bool)
-                    for _, d in t.perm:
-                        is_dst[d] = True
-                    buf = jnp.where(jnp.asarray(is_dst)[idx],
-                                    buf.at[recv_ids].set(got), buf)
-        return _unflatten(buf.reshape(-1), n, shape)
+        chunk = flat.shape[0] // n_chunks
+
+        def offset(first_chunks: np.ndarray) -> Array:
+            return jnp.asarray(first_chunks * chunk, dtype=jnp.int32)[idx]
+
+        for perm, reduce, send0, recv0, k, dst in hops:
+            size = k * chunk
+            piece = jax.lax.dynamic_slice_in_dim(flat, offset(send0), size)
+            payload = encode(piece) if encode is not None else piece
+            got = jax.tree.map(
+                lambda a: jax.lax.ppermute(a, axis_name, perm), payload)
+            if decode is not None:
+                got = decode(got, piece)
+            at = offset(recv0)
+            if reduce:
+                # non-destinations receive zeros: accumulating is a no-op
+                got = jax.lax.dynamic_slice_in_dim(flat, at, size) + got
+            elif dst is not None:
+                got = jnp.where(jnp.asarray(dst)[idx], got,
+                                jax.lax.dynamic_slice_in_dim(flat, at, size))
+            flat = jax.lax.dynamic_update_slice_in_dim(flat, got, at, axis=0)
+        return _unflatten(flat, n, shape)
 
     return fn
 
