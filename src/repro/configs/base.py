@@ -32,6 +32,17 @@ class ModelConfig:
     use_rope: bool = True
     rope_theta: float = 10000.0
     partial_rotary_factor: float = 1.0  # GLM: 0.5
+    #: YaRN (DeepSeek-V2's ``rope_scaling``): context ``factor`` over the
+    #: ``original_len`` the model was first trained at, frequencies ramped
+    #: between the correction dims of ``beta_fast`` and ``beta_slow``
+    #: rotations, ``mscale_all_dim`` in the softmax scale.  Cos/sin stay
+    #: unscaled: their factor, mscale ÷ mscale_all_dim, is 1 in DeepSeek-V2.
+    #: Factor 0: plain RoPE.
+    rope_yarn_factor: float = 0.0
+    rope_yarn_original_len: int = 4096
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_yarn_mscale_all_dim: float = 0.0
     sliding_window: Optional[int] = None  # danube SWA
     qkv_bias: bool = False  # codeqwen/qwen1.5
     # MLA (deepseek)
@@ -41,11 +52,15 @@ class ModelConfig:
     mla_v_dim: int = 128
 
     # MoE
+    #: ``moe_experts`` routed experts are held here: under expert
+    #: parallelism a chip's share of the router's ``moe_router_experts``
+    #: (0: the router's width is ``moe_experts``, all held)
     moe_experts: int = 0
+    moe_router_experts: int = 0
     moe_top_k: int = 0
     moe_shared_experts: int = 0
     moe_d_ff: int = 0  # per-expert FFN width
-    moe_capacity_factor: float = 1.25
+    norm_topk_prob: bool = True  # renormalise the top-k gates (DBRX); DeepSeek-V2 does not
     aux_loss_weight: float = 0.01
 
     # SSM (mamba2)
@@ -84,6 +99,9 @@ class ModelConfig:
     remat_policy: str = "full"
 
     def __post_init__(self):
+        object.__setattr__(self, "block_pattern", tuple(self.block_pattern))
+        if not self.moe_router_experts:
+            object.__setattr__(self, "moe_router_experts", self.moe_experts)
         if self.head_dim == 0 and self.n_heads:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         if not self.block_pattern and self.n_layers:
@@ -126,12 +144,14 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Params touched per token (MoE: top-k + shared experts only)."""
+        """Params touched per token (MoE: shared experts and, of the held
+        experts, the top-k share that routes here: top_k · held / router)."""
         if self.moe_experts == 0:
             return self.param_count()
         d = self.d_model
         full_expert = 3 * d * self.moe_d_ff
-        inactive = (self.moe_experts - self.moe_top_k) * full_expert
+        routed_here = self.moe_top_k * self.moe_experts / self.moe_router_experts
+        inactive = round((self.moe_experts - routed_here) * full_expert)
         n_moe_layers = sum(1 for k in self.block_pattern if k in ("moe", "mla_moe"))
         return self.param_count() - n_moe_layers * inactive
 
@@ -146,8 +166,8 @@ class ModelConfig:
         mla = (d * self.n_heads * (self.mla_qk_nope_dim + self.mla_qk_rope_dim)
                + d * self.mla_kv_lora_rank + d * self.mla_qk_rope_dim
                + self.mla_kv_lora_rank * self.n_heads * (self.mla_qk_nope_dim + self.mla_v_dim)
-               + self.n_heads * self.mla_v_dim * d)
-        moe = self.moe_experts * 3 * d * self.moe_d_ff + d * self.moe_experts
+               + self.n_heads * self.mla_v_dim * d + self.mla_kv_lora_rank)
+        moe = self.moe_experts * 3 * d * self.moe_d_ff + d * self.moe_router_experts
         if self.moe_shared_experts:
             moe += 3 * d * (self.moe_shared_experts * self.moe_d_ff)
         if kind == "dense":

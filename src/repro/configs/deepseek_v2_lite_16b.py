@@ -2,6 +2,9 @@
 
 27L d_model=2048 16H d_ff=1408/expert vocab=102400, 64 routed experts
 top-6 + 2 shared, first layer dense (d_ff=10944)  [arXiv:2405.04434; hf]
+Gates are the raw top-6 softmax scores (``norm_topk_prob`` false), the
+balance loss is per sequence with α = 0.001 (``aux_loss_alpha``), and RoPE
+is YaRN (factor 40 over 4096 positions, β 32/1, mscale 0.707 for both).
 
 The MLA latent cache (rank 512 + 64 rope dims = 576/token) is the arch's
 serving-side contribution; ``decode_32k`` exercises it.
@@ -10,6 +13,8 @@ serving-side contribution; ``decode_32k`` exercises it.
 from repro.configs.base import ModelConfig
 
 ARCH_ID = "deepseek-v2-lite-16b"
+YARN = dict(rope_yarn_factor=40.0, rope_yarn_original_len=4096, rope_yarn_beta_fast=32.0,
+            rope_yarn_beta_slow=1.0, rope_yarn_mscale_all_dim=0.707)
 
 
 def config() -> ModelConfig:
@@ -22,8 +27,9 @@ def config() -> ModelConfig:
         mla_kv_lora_rank=512, mla_qk_nope_dim=128, mla_qk_rope_dim=64,
         mla_v_dim=128,
         moe_experts=64, moe_top_k=6, moe_shared_experts=2, moe_d_ff=1408,
+        norm_topk_prob=False, aux_loss_weight=0.001,
         rope_theta=10000.0, mlp_style="swiglu", norm="rmsnorm",
-        tie_embeddings=False,
+        tie_embeddings=False, **YARN,
     )
 
 
@@ -36,6 +42,7 @@ def smoke_config() -> ModelConfig:
         mla_kv_lora_rank=32, mla_qk_nope_dim=16, mla_qk_rope_dim=8,
         mla_v_dim=16,
         moe_experts=8, moe_top_k=2, moe_shared_experts=2, moe_d_ff=32,
+        norm_topk_prob=False, aux_loss_weight=0.001,
         rope_theta=10000.0, mlp_style="swiglu", norm="rmsnorm",
-        tie_embeddings=False,
+        tie_embeddings=False, **YARN,
     )

@@ -21,7 +21,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import apply_rope, dense_init
+from repro.models.layers import (apply_rope, dense_init, init_norm, rmsnorm,
+                                 yarn_frequencies, yarn_mscale)
 
 Array = jax.Array
 
@@ -297,8 +298,9 @@ def init_mla(rng: Array, d: int, n_heads: int, kv_lora_rank: int,
     return {
         # queries (lite model: no q-lora)
         "wq": dense_init(ks[0], (d, n_heads, qk_nope_dim + qk_rope_dim), dtype=dtype),
-        # latent KV compression
+        # latent KV compression, RMS-normalised (``kv_a_layernorm``)
         "w_dkv": dense_init(ks[1], (d, kv_lora_rank), dtype=dtype),
+        "kv_norm": init_norm("rmsnorm", kv_lora_rank),
         "w_kpe": dense_init(ks[2], (d, qk_rope_dim), dtype=dtype),  # shared across heads
         # decompression
         "w_uk": dense_init(ks[3], (kv_lora_rank, n_heads, qk_nope_dim), dtype=dtype),
@@ -307,23 +309,60 @@ def init_mla(rng: Array, d: int, n_heads: int, kv_lora_rank: int,
     }
 
 
+def _mla_rope(x: Array, positions: Array, cfg) -> Array:
+    """RoPE over MLA's rope dims, with YaRN's frequencies where the config
+    sets a YaRN factor."""
+    f = cfg.rope_yarn_factor
+    if not f:
+        return apply_rope(x, positions, cfg.rope_theta)
+    return apply_rope(x, positions, inv_freq=yarn_frequencies(
+        x.shape[-1], cfg.rope_theta, f, cfg.rope_yarn_original_len,
+        cfg.rope_yarn_beta_fast, cfg.rope_yarn_beta_slow))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/√(qk_nope + qk_rope), times YaRN's mscale(mscale_all_dim)² where set."""
+    scale = 1.0 / math.sqrt(cfg.mla_qk_nope_dim + cfg.mla_qk_rope_dim)
+    if cfg.rope_yarn_factor:
+        scale *= yarn_mscale(cfg.rope_yarn_factor, cfg.rope_yarn_mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_query(p: dict, x: Array, positions: Array, cfg) -> Array:
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(x.dtype))
+    q_nope, q_pe = q[..., :cfg.mla_qk_nope_dim], q[..., cfg.mla_qk_nope_dim:]
+    return jnp.concatenate([q_nope, _mla_rope(q_pe, positions, cfg)], axis=-1)
+
+
+def _mla_compress(p: dict, x: Array, positions: Array, cfg) -> tuple[Array, Array]:
+    """What a server caches per token: the normalised latent c_kv [B,S,rank]
+    and the rotated key k_pe [B,S,rope] that all heads share."""
+    dt = x.dtype
+    with jax.named_scope("latent"):
+        c_kv = rmsnorm(jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt)),
+                       p["kv_norm"]["w"])
+    k_pe = jnp.einsum("bsd,dk->bsk", x, p["w_kpe"].astype(dt))
+    return c_kv, _mla_rope(k_pe[:, :, None, :], positions, cfg)[:, :, 0, :]
+
+
+def _mla_expand(p: dict, c_kv: Array, k_pe: Array, cfg) -> tuple[Array, Array]:
+    """Per-head keys [B,S,H,nope+rope] and values [B,S,H,v] from the latent."""
+    dt = c_kv.dtype
+    with jax.named_scope("latent"):
+        k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"].astype(dt))
+        v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"].astype(dt))
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (*k_nope.shape[:3], cfg.mla_qk_rope_dim))
+    return jnp.concatenate([k_nope, k_pe], axis=-1), v
+
+
 def mla_forward(p: dict, x: Array, positions: Array, cfg,
                 use_chunked: Optional[bool] = None) -> Array:
     """Full-sequence MLA. The latent c_kv (rank 512) + shared k_pe (64) are
     what a production server caches — 576 floats/token vs 2·H·D = 4096."""
-    dt = x.dtype
-    b, s, _ = x.shape
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    q_nope, q_pe = q[..., :cfg.mla_qk_nope_dim], q[..., cfg.mla_qk_nope_dim:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
-    c_kv = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt))
-    k_pe = apply_rope(jnp.einsum("bsd,dk->bsk", x, p["w_kpe"].astype(dt))[:, :, None, :],
-                      positions, cfg.rope_theta)  # [B,S,1,rope]
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"].astype(dt))
-    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"].astype(dt))
-    k = jnp.concatenate([k_nope, jnp.broadcast_to(k_pe, (*k_nope.shape[:3], cfg.mla_qk_rope_dim))], axis=-1)
-    qc = jnp.concatenate([q_nope, q_pe], axis=-1)
-    scale = 1.0 / math.sqrt(cfg.mla_qk_nope_dim + cfg.mla_qk_rope_dim)
+    s = x.shape[1]
+    qc = _mla_query(p, x, positions, cfg)
+    k, v = _mla_expand(p, *_mla_compress(p, x, positions, cfg), cfg)
+    scale = mla_softmax_scale(cfg)
     if use_chunked is None:
         use_chunked = s * s > cfg.dense_attn_limit
     if use_chunked:
@@ -332,7 +371,7 @@ def mla_forward(p: dict, x: Array, positions: Array, cfg,
     else:
         mask = build_mask(positions, positions, "causal")
         out = dense_attention(qc, k, v, mask, scale=scale)
-    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt))
+    return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
 
 
 def init_mla_cache(batch: int, max_len: int, kv_lora_rank: int, rope_dim: int,
@@ -345,30 +384,20 @@ def init_mla_cache(batch: int, max_len: int, kv_lora_rank: int, rope_dim: int,
 
 
 def mla_decode(p: dict, x: Array, cache: dict, position: Array, cfg) -> tuple[Array, dict]:
-    """One-token MLA decode against the compressed latent cache."""
+    """One-token MLA decode against the latent cache, which holds the
+    normalised c_kv and the rotated k_pe, as ``mla_forward`` computes them."""
     dt = x.dtype
     b = x.shape[0]
     pos_b = jnp.broadcast_to(position[None], (b,))[:, None]
-    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(dt))
-    q_nope, q_pe = q[..., :cfg.mla_qk_nope_dim], q[..., cfg.mla_qk_nope_dim:]
-    q_pe = apply_rope(q_pe, pos_b, cfg.rope_theta)
-    c_new = jnp.einsum("bsd,dr->bsr", x, p["w_dkv"].astype(dt))
-    kpe_new = apply_rope(jnp.einsum("bsd,dk->bsk", x, p["w_kpe"].astype(dt))[:, :, None, :],
-                         pos_b, cfg.rope_theta)[:, :, 0, :]
+    qc = _mla_query(p, x, pos_b, cfg)
+    c_new, kpe_new = _mla_compress(p, x, pos_b, cfg)
     slot = position % cache["c_kv"].shape[1]
     cache = {
         "c_kv": jax.lax.dynamic_update_slice(cache["c_kv"], c_new.astype(cache["c_kv"].dtype), (0, slot, 0)),
         "k_pe": jax.lax.dynamic_update_slice(cache["k_pe"], kpe_new.astype(cache["k_pe"].dtype), (0, slot, 0)),
         "pos": jax.lax.dynamic_update_slice(cache["pos"], jnp.broadcast_to(position, (b, 1)).astype(jnp.int32), (0, slot)),
     }
-    c_kv = cache["c_kv"].astype(dt)
-    k_nope = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uk"].astype(dt))
-    v = jnp.einsum("bsr,rhk->bshk", c_kv, p["w_uv"].astype(dt))
-    k_pe = jnp.broadcast_to(cache["k_pe"].astype(dt)[:, :, None, :],
-                            (*k_nope.shape[:3], cfg.mla_qk_rope_dim))
-    k = jnp.concatenate([k_nope, k_pe], axis=-1)
-    qc = jnp.concatenate([q_nope, q_pe], axis=-1)
-    scale = 1.0 / math.sqrt(cfg.mla_qk_nope_dim + cfg.mla_qk_rope_dim)
+    k, v = _mla_expand(p, cache["c_kv"].astype(dt), cache["k_pe"].astype(dt), cfg)
     mask = build_mask(pos_b, cache["pos"], "causal")
-    out = dense_attention(qc, k, v, mask, scale=scale)
+    out = dense_attention(qc, k, v, mask, scale=mla_softmax_scale(cfg))
     return jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(dt)), cache

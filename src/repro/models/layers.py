@@ -72,16 +72,41 @@ def rope_frequencies(head_dim: int, theta: float = 10000.0,
     return 1.0 / (theta ** (jnp.arange(0, rd, 2, dtype=jnp.float32) / rd))
 
 
+def yarn_frequencies(dim: int, theta: float, factor: float, original_len: int,
+                     beta_fast: float, beta_slow: float) -> Array:
+    """YaRN's inverse frequencies over ``dim`` rotary dims (DeepSeek-V2's
+    ``rope_scaling``, arXiv:2309.00071): pair ``i`` keeps its RoPE frequency
+    below the dim at which a frequency turns ``beta_fast`` times over
+    ``original_len`` positions, takes it ÷ ``factor`` above the dim of
+    ``beta_slow`` turns, and a linear blend of the two between."""
+    def correction_dim(turns: float) -> float:
+        return dim * math.log(original_len / (turns * 2 * math.pi)) / (2 * math.log(theta))
+
+    lo = max(math.floor(correction_dim(beta_fast)), 0)
+    hi = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if hi == lo:
+        hi += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo) / (hi - lo), 0.0, 1.0)
+    inv = rope_frequencies(dim, theta)
+    return inv / factor * ramp + inv * (1.0 - ramp)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature 0.1 · mscale · ln(factor) + 1 (1 without scaling)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
 def apply_rope(x: Array, positions: Array, theta: float = 10000.0,
-               rotary_dim: Optional[int] = None) -> Array:
+               rotary_dim: Optional[int] = None, inv_freq: Optional[Array] = None) -> Array:
     """Rotate ``x`` [..., S, H, D] by position. ``positions``: [..., S].
 
     Supports partial rotary (GLM-style): only the first ``rotary_dim`` dims
-    are rotated, the remainder passes through.
+    are rotated, the remainder passes through.  ``inv_freq`` replaces
+    RoPE's frequencies (YaRN's).
     """
     d = x.shape[-1]
     rd = rotary_dim or d
-    inv = rope_frequencies(d, theta, rd)  # [rd/2]
+    inv = rope_frequencies(d, theta, rd) if inv_freq is None else inv_freq  # [rd/2]
     ang = positions[..., :, None].astype(jnp.float32) * inv  # [..., S, rd/2]
     cos = jnp.cos(ang)[..., None, :]  # [..., S, 1, rd/2]
     sin = jnp.sin(ang)[..., None, :]

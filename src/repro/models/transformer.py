@@ -57,33 +57,19 @@ def _init_block(rng: Array, kind: str, cfg: ModelConfig) -> dict:
     d = cfg.d_model
     pd = cfg.pdtype
     ks = jax.random.split(rng, 4)
-    if kind in ("dense", "moe"):
-        p = {
-            "ln1": init_norm(cfg.norm, d),
-            "attn": attn.init_attention(ks[0], d, cfg.n_heads, cfg.n_kv_heads,
-                                        cfg.head_dim, cfg.qkv_bias, pd),
-            "ln2": init_norm(cfg.norm, d),
-        }
-        if kind == "dense":
+    if kind in ("dense", "moe", "mla_dense", "mla_moe"):
+        if kind.startswith("mla"):
+            a = attn.init_mla(ks[0], d, cfg.n_heads, cfg.mla_kv_lora_rank,
+                              cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim, cfg.mla_v_dim, pd)
+        else:
+            a = attn.init_attention(ks[0], d, cfg.n_heads, cfg.n_kv_heads,
+                                    cfg.head_dim, cfg.qkv_bias, pd)
+        p = {"ln1": init_norm(cfg.norm, d), "attn": a, "ln2": init_norm(cfg.norm, d)}
+        if kind.endswith("dense"):
             p["mlp"] = init_mlp(ks[1], d, cfg.d_ff, cfg.mlp_style, pd)
         else:
             p["moe"] = moe_lib.init_moe(ks[1], d, cfg.moe_d_ff, cfg.moe_experts,
-                                        cfg.moe_shared_experts,
-                                        cfg.moe_shared_experts * cfg.moe_d_ff or None, pd)
-        return p
-    if kind in ("mla_dense", "mla_moe"):
-        p = {
-            "ln1": init_norm(cfg.norm, d),
-            "attn": attn.init_mla(ks[0], d, cfg.n_heads, cfg.mla_kv_lora_rank,
-                                  cfg.mla_qk_nope_dim, cfg.mla_qk_rope_dim,
-                                  cfg.mla_v_dim, pd),
-            "ln2": init_norm(cfg.norm, d),
-        }
-        if kind == "mla_dense":
-            p["mlp"] = init_mlp(ks[1], d, cfg.d_ff, cfg.mlp_style, pd)
-        else:
-            p["moe"] = moe_lib.init_moe(ks[1], d, cfg.moe_d_ff, cfg.moe_experts,
-                                        cfg.moe_shared_experts,
+                                        cfg.moe_router_experts, cfg.moe_shared_experts,
                                         cfg.moe_shared_experts * cfg.moe_d_ff or None, pd)
         return p
     if kind == "mamba2":
@@ -162,34 +148,23 @@ def _block_forward(kind: str, p: dict, x: Array, positions: Array,
     block's ops, forward, backward and recomputed alike."""
     with jax.named_scope("block"):
         aux = jnp.zeros((), jnp.float32)
-        if kind in ("dense", "moe"):
+        if kind in ("dense", "moe", "mla_dense", "mla_moe"):
             h = apply_norm(cfg.norm, p["ln1"], x)
             with jax.named_scope("attention"):
-                a = attn.attention_forward(p["attn"], h, positions, cfg, mask_kind,
-                                           prefix_len, use_pallas=cfg.use_pallas)
+                if kind.startswith("mla"):
+                    a = attn.mla_forward(p["attn"], h, positions, cfg)
+                else:
+                    a = attn.attention_forward(p["attn"], h, positions, cfg, mask_kind,
+                                               prefix_len, use_pallas=cfg.use_pallas)
             x = x + a
             h = apply_norm(cfg.norm, p["ln2"], x)
-            if kind == "dense":
+            if kind.endswith("dense"):
                 with jax.named_scope("mlp"):
                     y = apply_mlp(p["mlp"], h, cfg.mlp_style)
             else:
                 with jax.named_scope("moe"):
                     y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k,
-                                               cfg.moe_capacity_factor)
-            x = x + y
-        elif kind in ("mla_dense", "mla_moe"):
-            h = apply_norm(cfg.norm, p["ln1"], x)
-            with jax.named_scope("attention"):
-                a = attn.mla_forward(p["attn"], h, positions, cfg)
-            x = x + a
-            h = apply_norm(cfg.norm, p["ln2"], x)
-            if kind == "mla_dense":
-                with jax.named_scope("mlp"):
-                    y = apply_mlp(p["mlp"], h, cfg.mlp_style)
-            else:
-                with jax.named_scope("moe"):
-                    y, aux = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k,
-                                               cfg.moe_capacity_factor)
+                                               cfg.norm_topk_prob)
             x = x + y
         elif kind == "mamba2":
             h = apply_norm(cfg.norm, p["ln1"], x)
@@ -471,25 +446,16 @@ def _flatten_layer_params(params: PyTree, cfg: ModelConfig) -> list[tuple[str, d
 
 def _decode_block(kind: str, p: dict, x: Array, cache, position: Array,
                   cfg: ModelConfig, params: PyTree):
-    if kind in ("dense", "moe"):
+    if kind in ("dense", "moe", "mla_dense", "mla_moe"):
         h = apply_norm(cfg.norm, p["ln1"], x)
-        a, cache = attn.decode_attention(p["attn"], h, cache, position, cfg)
+        decode = attn.mla_decode if kind.startswith("mla") else attn.decode_attention
+        a, cache = decode(p["attn"], h, cache, position, cfg)
         x = x + a
         h = apply_norm(cfg.norm, p["ln2"], x)
-        if kind == "dense":
+        if kind.endswith("dense"):
             x = x + apply_mlp(p["mlp"], h, cfg.mlp_style)
         else:
-            y, _ = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k, 2.0)
-            x = x + y
-    elif kind in ("mla_dense", "mla_moe"):
-        h = apply_norm(cfg.norm, p["ln1"], x)
-        a, cache = attn.mla_decode(p["attn"], h, cache, position, cfg)
-        x = x + a
-        h = apply_norm(cfg.norm, p["ln2"], x)
-        if kind == "mla_dense":
-            x = x + apply_mlp(p["mlp"], h, cfg.mlp_style)
-        else:
-            y, _ = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k, 2.0)
+            y, _ = moe_lib.apply_moe(p["moe"], h, cfg.moe_top_k, cfg.norm_topk_prob)
             x = x + y
     elif kind == "mamba2":
         h = apply_norm(cfg.norm, p["ln1"], x)
