@@ -13,6 +13,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -37,8 +38,9 @@ def run() -> list[str]:
         q.transpose(0, 2, 1, 3).reshape(b * h, s, d),
         k.transpose(0, 2, 1, 3).reshape(b * kv, s, d),
         v.transpose(0, 2, 1, 3).reshape(b * kv, s, d)), q, k, v)
-    vmem_kb = (128 * d * 3 + 128 * 128) * 4 / 1024  # q,k,v tiles + scores
-    flops_per_byte = (2 * 128 * 128 * d * 2) / ((128 * d * 3 + 128 * d) * 4)
+    blk = min(fa.BLOCK, s)  # the kernel's q and kv block at this length
+    vmem_kb = (blk * d * 3 + blk * blk) * 4 / 1024  # q,k,v tiles + scores
+    flops_per_byte = (2 * blk * blk * d * 2) / ((blk * d * 3 + blk * d) * 4)
     lines.append(f"bench_kernels/flash_attention/ref_jnp,{t_ref:.0f},")
     lines.append(f"bench_kernels/flash_attention/vmem_per_step_kb,,{vmem_kb:.0f}")
     lines.append(f"bench_kernels/flash_attention/arith_intensity,,{flops_per_byte:.1f}")

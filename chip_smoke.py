@@ -66,10 +66,12 @@ BF16_REL = 2e-2
 #: f32 all-reduce vs psum: summation order is the only difference
 F32_REL = 1e-5
 
-#: (name, (batch, seq, q heads, kv heads, head_dim), sliding window)
+#: (name, (batch, seq, q heads, kv heads, qk head_dim, v head_dim),
+#: sliding window, softmax scale or None for 1/√qk head_dim)
 ATTN_CASES = (
-    ("bert-large", (8, 512, 16, 16, 64), None),
-    ("h2o-danube-1.8b gqa+swa", (1, 4096, 32, 8, 80), 4096),
+    ("bert-large", (8, 512, 16, 16, 64, 64), None, None),
+    ("h2o-danube-1.8b gqa+swa", (1, 4096, 32, 8, 80, 80), 1024, None),
+    ("deepseek-v2-lite mla", (2, 4096, 16, 16, 192, 128), None, 0.1147),
 )
 RMSNORM_SHAPE = (4096, 2560)  # danube d_model
 QUANT_N = 1 << 24  # a 64 MB fp32 gradient bucket
@@ -167,33 +169,51 @@ def _rel_err(out, expect) -> float:
     return float(jnp.max(jnp.abs(out - expect)) / jnp.max(jnp.abs(expect)))
 
 
-@functools.partial(jax.jit, static_argnames="window")
-def _ref_attention_bshd(q, k, v, window):
-    b, s, h, d = q.shape
-    kv = k.shape[2]
-    to_bh = lambda t, n: t.transpose(0, 2, 1, 3).reshape(b * n, s, d)
+def _ref_attention_bshd(q, k, v, *, causal, window, scale):
+    b, s, h, _ = q.shape
+    dv = v.shape[-1]
+    to_bh = lambda t: t.transpose(0, 2, 1, 3).reshape(-1, s, t.shape[-1])
     with jax.default_matmul_precision("highest"):
-        out = ref.reference_attention(to_bh(q, h), to_bh(k, kv), to_bh(v, kv),
-                                      causal=True, window=window)
-    return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+        out = ref.reference_attention(to_bh(q), to_bh(k), to_bh(v), causal=causal,
+                                      window=window, scale=scale)
+    return out.reshape(b, h, s, dv).transpose(0, 2, 1, 3)
+
+
+def _attention_grads(attn):
+    """The gradients of Σ attn(q, k, v) · ct with respect to q, k and v."""
+    def loss(q, k, v, ct, **static):
+        return jnp.sum(attn(q, k, v, **static).astype(jnp.float32) * ct)
+    return jax.jit(jax.grad(loss, (0, 1, 2)),
+                   static_argnames=("causal", "window", "scale"))
 
 
 def kernel_phase(*, attn_cases=ATTN_CASES, rmsnorm_shape=RMSNORM_SHAPE,
                  quant_n: int = QUANT_N, require_mosaic: bool = True) -> dict:
     """Each kernel through ``repro.kernels.ops`` against its oracle; returns
-    the errors by kernel.  ``require_mosaic=False`` only for a CPU test."""
+    the errors by kernel (flash attention: its output and its gradients).
+    ``require_mosaic=False`` only for a CPU test."""
     key = jax.random.PRNGKey(SEED)
     errs = {}
-    for i, (name, (b, s, h, kv, d), window) in enumerate(attn_cases):
-        kq, kk, kvv = jax.random.split(jax.random.fold_in(key, i), 3)
+    for i, (name, (b, s, h, kv, d, dv), window, scale) in enumerate(attn_cases):
+        kq, kk, kvv, kct = jax.random.split(jax.random.fold_in(key, i), 4)
         q = jax.random.normal(kq, (b, s, h, d), jnp.bfloat16)
         k = jax.random.normal(kk, (b, s, kv, d), jnp.bfloat16)
-        v = jax.random.normal(kvv, (b, s, kv, d), jnp.bfloat16)
+        v = jax.random.normal(kvv, (b, s, kv, dv), jnp.bfloat16)
+        ct = jax.random.normal(kct, (b, s, h, dv), jnp.float32)
+        static = dict(causal=True, window=window, scale=scale)
         label = f"flash_attention {name}"
-        fa = _compile(ops.flash_attention, q, k, v, causal=True, window=window,
-                      require_mosaic=require_mosaic, name=label)
-        errs[label] = _rel_err(fa(q, k, v), _ref_attention_bshd(q, k, v, window))
+        fa = _compile(jax.jit(ops.flash_attention, static_argnames=tuple(static)),
+                      q, k, v, **static, require_mosaic=require_mosaic, name=label)
+        oracle = jax.jit(_ref_attention_bshd, static_argnames=tuple(static))
+        errs[label] = _rel_err(fa(q, k, v), oracle(q, k, v, **static))
         check(errs[label] <= BF16_REL, f"{label}: rel err {errs[label]}")
+        grads = _compile(_attention_grads(ops.flash_attention), q, k, v, ct,
+                         **static, require_mosaic=require_mosaic,
+                         name=f"{label} grad")(q, k, v, ct)
+        expects = _attention_grads(_ref_attention_bshd)(q, k, v, ct, **static)
+        for part, g, e in zip(("dq", "dk", "dv"), grads, expects):
+            errs[f"{label} {part}"] = err = _rel_err(g, e)
+            check(err <= BF16_REL, f"{label} {part}: rel err {err}")
 
     kx, kw, kg = jax.random.split(jax.random.fold_in(key, 100), 3)
     x = jax.random.normal(kx, rmsnorm_shape, jnp.bfloat16)

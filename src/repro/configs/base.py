@@ -91,7 +91,7 @@ class ModelConfig:
     kv_cache_dtype: str = "bfloat16"  # "int8" → KIVI-style quantized cache
     dense_attn_limit: int = 8192 * 8192  # Sq·Skv above which attention chunks
     attn_chunk: int = 1024
-    use_pallas: bool = False
+    use_pallas: bool = False  # read by nothing: the platform chooses attention
     unroll_layers: bool = False  # roofline mode: exact per-layer HLO accounting
     remat: bool = True
     #: "full" re-runs the whole block in backward; "dots" saves matmul

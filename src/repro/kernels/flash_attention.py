@@ -1,134 +1,94 @@
-"""Flash attention Pallas TPU kernel (causal / bidirectional, GQA, SWA).
+"""Flash attention on TPU: the splash kernel shipped with JAX, with its backward.
 
-TPU-native design (not a CUDA port):
-  * grid = (batch·q_heads, q_blocks, kv_blocks) with the KV dimension
-    innermost ("arbitrary" semantics) so the fp32 accumulator, running max
-    and denominator live in **VMEM scratch** across KV iterations;
-  * Q/K/V blocks are staged HBM→VMEM by ``BlockSpec`` index maps; the GQA
-    kv-head broadcast happens in the *index map* (q-head ÷ group size), so
-    grouped KV is never materialized per-head;
-  * block shapes default to (128, head_dim) — MXU-aligned (≥ 128 lanes);
-  * causal + sliding-window masking via block-position iota; fully-masked
-    blocks still iterate but skip the matmul through ``@pl.when``.
+One fused kernel serves training and prefill (causal, sliding-window and
+bidirectional/cross attention, GQA, a value width other than the key
+width as MLA has):
 
-Validated against ``ref.reference_attention`` in interpret mode on CPU
-(``tests/test_kernels.py``) and on the chip (``chip_smoke.py``);
-``tests/test_tpu_compile.py`` compiles it for a described v5e.
+  * online softmax over KV blocks held in VMEM; bf16 operands into the
+    MXU with f32 accumulation; the per-row log-sum-exp is saved for the
+    backward, so no [Sq, Skv] array is ever written to HBM;
+  * the mask is known when the program is traced, so blocks that are
+    entirely dead under the causal or window mask are skipped, forward
+    and backward (splash's block-sparse ``MaskInfo``);
+  * the backward is a ``jax.custom_vjp`` over splash's dq and dkv kernels;
+  * GQA is native: q heads index their KV head in the kernel's index map.
+
+Lengths that do not tile into the block are padded: padded keys sit after
+every real query (causal) or carry another segment id (bidirectional), so
+no real row sees them.
+
+Validated against ``ref.reference_attention`` in interpret mode on CPU,
+forward and gradients (``tests/test_kernels.py``), and on the chip
+(``chip_smoke.py``); ``tests/test_tpu_compile.py`` compiles it for a
+described v5e, alone and inside the train step.
 """
 
 from __future__ import annotations
 
-import functools
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-NEG_INF = -1e30
+#: largest q and kv block; the lane width (128) is the smallest
+BLOCK = 512
+LANES = 128
 
 
-def _attn_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                 scale: float, causal: bool, window: Optional[int],
-                 bq: int, bk: int, seq_q: int, seq_kv: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    n_kv = pl.num_programs(2)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
-    @pl.when(ki == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
 
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = ki * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-    mask = k_pos < seq_kv  # padding
-    if causal:
-        mask &= k_pos <= q_pos
-    if window is not None:
-        mask &= (q_pos - k_pos) < window
+def _block(s: int) -> int:
+    return min(BLOCK, _round_up(s, LANES))
 
-    # block-level early out: skip matmuls when the whole block is masked
-    block_live = jnp.bool_(True)
-    if causal:
-        block_live &= (ki * bk) <= (qi * bq + bq - 1)
-    if window is not None:
-        block_live &= ((qi * bq) - (ki * bk + bk - 1)) < window
 
-    @pl.when(block_live)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale  # [bq,bk]
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_ref[...] = m_new
-
-    @pl.when(ki == n_kv - 1)
-    def _finish():
-        denom = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+def _kernel(heads: int, sq: int, skv: int, causal: bool, window: Optional[int],
+            bq: int, bkv: int, interpret: bool):
+    """The splash kernel for one padded shape and mask.  Its block map is
+    numpy work at trace time, which splash caches by mask."""
+    if not causal:
+        mask = splash.FullMask((sq, skv))
+    elif window is None:
+        mask = splash.CausalMask((sq, skv))
+    else:  # q − k < window, k ≤ q
+        mask = splash.LocalMask((sq, skv), window_size=(window - 1, 0), offset=0)
+    sizes = splash.BlockSizes(
+        block_q=bq, block_kv=bkv, block_kv_compute=bkv,
+        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkv,
+        block_q_dq=bq, block_kv_dq=bkv)
+    return splash.make_splash_mha(
+        splash.MultiHeadMask([mask] * heads), block_sizes=sizes,
+        head_shards=1, q_seq_shards=1, interpret=interpret)
 
 
 def flash_attention_bhsd(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                         causal: bool = True, window: Optional[int] = None,
-                         scale: Optional[float] = None,
-                         block_q: int = 128, block_k: int = 128,
+                         causal: bool, window: Optional[int], scale: float,
                          interpret: bool) -> jax.Array:
-    """q: [BH, Sq, D]; k/v: [BKV, Skv, D] with BH = BKV·n_rep.  → [BH, Sq, D].
-
-    BH-major layout: head index varies fastest within a batch entry so the
-    GQA index map is ``bh // n_rep`` after batch alignment.
-    """
-    bh, sq, d = q.shape
-    bkv, skv, _ = k.shape
-    assert bh % bkv == 0, (bh, bkv)
-    n_rep = bh // bkv
-    scale = scale if scale is not None else 1.0 / math.sqrt(d)
-    bq = min(block_q, sq)
-    bk = min(block_k, skv)
-    sq_pad = (-sq) % bq
-    skv_pad = (-skv) % bk
-    if sq_pad:
-        q = jnp.pad(q, ((0, 0), (0, sq_pad), (0, 0)))
-    if skv_pad:
-        k = jnp.pad(k, ((0, 0), (0, skv_pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, skv_pad), (0, 0)))
-    grid = (bh, (sq + sq_pad) // bq, (skv + skv_pad) // bk)
-
-    kernel = functools.partial(
-        _attn_kernel, scale=scale, causal=causal, window=window,
-        bq=bq, bk=bk, seq_q=sq, seq_kv=skv)
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, n_rep=n_rep: (b // n_rep, j, 0)),
-            pl.BlockSpec((1, bk, d), lambda b, i, j, n_rep=n_rep: (b // n_rep, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq + sq_pad, d), q.dtype),
-        scratch_shapes=[
-            _vmem((bq, d), jnp.float32),
-            _vmem((bq,), jnp.float32),
-            _vmem((bq,), jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
-    return out[:, :sq]
-
-
-def _vmem(shape, dtype):
-    """Explicit VMEM scratch spec (also honored by the interpreter)."""
-    from jax.experimental.pallas import tpu as pltpu
-    return pltpu.VMEM(shape, dtype)
+    """q [B, H, Sq, Dk], k [B, KV, Skv, Dk], v [B, KV, Skv, Dv], H a multiple
+    of KV; causal needs Sq == Skv (positions 0..S−1 on both sides).
+    → [B, H, Sq, Dv] in q's dtype."""
+    b, h, sq, _ = q.shape
+    skv = k.shape[2]
+    if causal and sq != skv:
+        raise ValueError(f"causal flash attention needs Sq == Skv, got {sq}, {skv}")
+    if window is not None and not causal:
+        raise ValueError("a window needs a causal mask")
+    if window is not None and window >= sq:
+        window = None  # a window as long as the sequence is plain causal
+    bq, bkv = _block(sq), _block(skv)
+    sq_p, skv_p = _round_up(sq, bq), _round_up(skv, bkv)
+    q = jnp.pad(q * jnp.asarray(scale, q.dtype),
+                ((0, 0), (0, 0), (0, sq_p - sq), (0, 0)))
+    k = jnp.pad(k, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
+    v = jnp.pad(v, ((0, 0), (0, 0), (0, skv_p - skv), (0, 0)))
+    kernel = _kernel(h, sq_p, skv_p, causal, window, bq, bkv, interpret)
+    if causal or skv_p == skv:
+        out = jax.vmap(kernel)(q, k, v)
+    else:  # keys past Skv carry segment 1, which no query has
+        seg = splash.SegmentIds(
+            q=jnp.zeros((sq_p,), jnp.int32),
+            kv=(jnp.arange(skv_p) >= skv).astype(jnp.int32))
+        out = jax.vmap(lambda q, k, v: kernel(q, k, v, segment_ids=seg))(q, k, v)
+    return out[:, :, :sq]

@@ -133,7 +133,9 @@ def make_train_step(cfg: ModelConfig, policy: ShardingPolicy,
 
     if comm == "xla":
         def step(params, opt_state, batch):
-            loss, grads = grad_fn(params, batch)
+            # the mesh in context lets a Pallas kernel map itself over it
+            with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+                loss, grads = grad_fn(params, batch)
             params, opt_state = adamw_update(params, grads, opt_state, opt_cfg)
             return params, opt_state, loss
 
@@ -206,7 +208,8 @@ def init_sharded_state(cfg: ModelConfig, policy: ShardingPolicy, rng,
 
 def make_prefill(cfg: ModelConfig, policy: ShardingPolicy):
     def prefill(params, batch):
-        logits, _ = tf.forward_logits(params, batch, cfg)
+        with jax.sharding.use_abstract_mesh(policy.mesh.abstract_mesh):
+            logits, _ = tf.forward_logits(params, batch, cfg)
         return logits
 
     params_shape = tf.param_shapes(cfg)

@@ -1,13 +1,15 @@
-"""Attention: GQA / MQA / sliding-window / MLA / cross, dense + chunked paths.
+"""Attention: GQA / MQA / sliding-window / MLA / cross, flash + dense + chunked paths.
 
 Three execution paths, all numerically interchangeable:
 
-  * dense    — materializes [Sq, Skv] scores; used for short sequences and
-               as the reference everywhere;
+  * flash    — on TPU, full-sequence causal (with or without a window) and
+               bidirectional attention, training and prefill, goes through
+               the fused kernel in ``repro.kernels`` (forward and backward;
+               no [Sq, Skv] array in HBM, dead causal blocks skipped);
+  * dense    — materializes [Sq, Skv] scores; used off TPU for short
+               sequences, for prefix masks and decode, and as the reference;
   * chunked  — online-softmax over KV chunks (lax.scan), bounding the score
-               working set to [Sq, chunk]; the pure-JAX analogue of flash
-               attention, used for 32k+ sequences in the dry-run;
-  * pallas   — the TPU kernel in ``repro.kernels`` (validated vs dense).
+               working set to [Sq, chunk]; off TPU above ``dense_attn_limit``.
 
 Decode maintains a KV cache; sliding-window archs (h2o-danube) use a ring
 buffer of ``window`` slots so a 500k-token stream needs O(window) memory.
@@ -21,6 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops as kops
 from repro.models.layers import (apply_rope, dense_init, init_norm, rmsnorm,
                                  yarn_frequencies, yarn_mscale)
 
@@ -183,20 +186,32 @@ def _project_qkv(p: dict, x: Array, xkv: Array, positions: Array,
     return q, k, v
 
 
+def _flash_takes(mask_kind: str) -> bool:
+    """On TPU the flash kernel takes causal self-attention (with or without
+    a window) and bidirectional attention; prefix masks, and every other
+    backend, keep the jnp paths."""
+    return kops.on_tpu() and mask_kind in ("causal", "bidirectional")
+
+
+def _flash(q: Array, k: Array, v: Array, mask_kind: str, window: Optional[int],
+           scale: Optional[float] = None) -> Array:
+    with jax.named_scope("flash"):
+        return kops.flash_attention(q, k, v, causal=mask_kind == "causal",
+                                    window=window, scale=scale)
+
+
 def attention_forward(p: dict, x: Array, positions: Array, cfg,
                       mask_kind: str = "causal", prefix_len: int = 0,
                       xkv: Optional[Array] = None,
-                      kv_positions: Optional[Array] = None,
-                      use_pallas: bool = False) -> Array:
-    """Full-sequence attention (train/prefill). ``xkv`` enables cross-attn."""
+                      kv_positions: Optional[Array] = None) -> Array:
+    """Full-sequence attention (train/prefill) over positions 0..S−1.
+    ``xkv`` enables cross-attn."""
     xkv = x if xkv is None else xkv
     kv_positions = positions if kv_positions is None else kv_positions
     q, k, v = _project_qkv(p, x, xkv, positions, kv_positions, cfg)
     window = cfg.sliding_window if mask_kind == "causal" else None
-    if use_pallas:
-        from repro.kernels import ops as kops
-        out = kops.flash_attention(q, k, v, causal=(mask_kind == "causal"),
-                                   window=window)
+    if _flash_takes(mask_kind):
+        out = _flash(q, k, v, mask_kind, window)
     elif x.shape[1] * xkv.shape[1] > cfg.dense_attn_limit:
         out = chunked_attention(q, k, v, positions, kv_positions, mask_kind,
                                 window, prefix_len, chunk=cfg.attn_chunk)
@@ -355,17 +370,17 @@ def _mla_expand(p: dict, c_kv: Array, k_pe: Array, cfg) -> tuple[Array, Array]:
     return jnp.concatenate([k_nope, k_pe], axis=-1), v
 
 
-def mla_forward(p: dict, x: Array, positions: Array, cfg,
-                use_chunked: Optional[bool] = None) -> Array:
-    """Full-sequence MLA. The latent c_kv (rank 512) + shared k_pe (64) are
-    what a production server caches — 576 floats/token vs 2·H·D = 4096."""
+def mla_forward(p: dict, x: Array, positions: Array, cfg) -> Array:
+    """Full-sequence MLA over positions 0..S−1. The latent c_kv (rank 512)
+    + shared k_pe (64) are what a production server caches — 576
+    floats/token vs 2·H·D = 4096."""
     s = x.shape[1]
     qc = _mla_query(p, x, positions, cfg)
     k, v = _mla_expand(p, *_mla_compress(p, x, positions, cfg), cfg)
     scale = mla_softmax_scale(cfg)
-    if use_chunked is None:
-        use_chunked = s * s > cfg.dense_attn_limit
-    if use_chunked:
+    if _flash_takes("causal"):
+        out = _flash(qc, k, v, "causal", None, scale)
+    elif s * s > cfg.dense_attn_limit:
         out = chunked_attention(qc, k, v, positions, positions, "causal",
                                 None, 0, chunk=cfg.attn_chunk, scale=scale)
     else:

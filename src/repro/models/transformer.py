@@ -155,7 +155,7 @@ def _block_forward(kind: str, p: dict, x: Array, positions: Array,
                     a = attn.mla_forward(p["attn"], h, positions, cfg)
                 else:
                     a = attn.attention_forward(p["attn"], h, positions, cfg, mask_kind,
-                                               prefix_len, use_pallas=cfg.use_pallas)
+                                               prefix_len)
             x = x + a
             h = apply_norm(cfg.norm, p["ln2"], x)
             if kind.endswith("dense"):

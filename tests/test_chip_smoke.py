@@ -65,9 +65,10 @@ def test_serve_phase_smoke(smoke):
 
 def test_kernel_phase_small(smoke):
     errs = smoke.kernel_phase(
-        attn_cases=(("gqa+swa", (1, 256, 4, 2, 80), 100),),
+        attn_cases=(("gqa+swa", (1, 256, 4, 2, 80, 80), 100, None),),
         rmsnorm_shape=(64, 256), quant_n=300_000, require_mosaic=False)
-    assert errs["flash_attention gqa+swa"] <= smoke.BF16_REL
+    for part in ("", " dq", " dk", " dv"):
+        assert errs["flash_attention gqa+swa" + part] <= smoke.BF16_REL
     assert errs["quantize_int8 payload mismatches"] == 0
     assert errs["dequantize_int8 mismatches"] == 0
 
